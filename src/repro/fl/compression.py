@@ -22,7 +22,7 @@ construction -- there is exactly one charging code path.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -289,6 +289,9 @@ class RoundAccountant:
         )
         self.packed_len = (sum(c.stats_len for c in self.codecs.values())
                            + sum(c.stats_len for c in self.dl_codecs.values()))
+        #: round -> {uplink path: its reduced stats}, what each charge was
+        #: computed from (exported as ``FLResult.extra["uplink_stats"]``)
+        self.uplink_stats: Dict[int, Dict[str, Tuple[int, ...]]] = {}
 
     def consume(self, packed: np.ndarray, ledger, rnd: int) -> None:
         """Charge the ledger for round ``rnd`` from its fetched stats row."""
@@ -301,9 +304,11 @@ class RoundAccountant:
                 f"registered codecs")
         off = 0
         bits = 32 * self.raw_scalars_per_client * self.n_sel
+        stats = self.uplink_stats[rnd] = {}
         for path, codec in self.codecs.items():
             red = packed[off: off + codec.stats_len]
             off += codec.stats_len
+            stats[path] = tuple(int(x) for x in red)
             bits += codec.charge_bits(red, self.n_sel)
             for k, v in codec.host_metrics(red, self.n_sel).items():
                 self.metrics[k] = self.metrics.get(k, 0) + v

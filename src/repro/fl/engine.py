@@ -61,7 +61,6 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.codecs import SERVER_CLIENT_ID
@@ -409,12 +408,12 @@ def _build_sharded_chunk(arch, lr: float, server_lr: float, codecs,
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
     def chunk_fn(params, cstate, shared, dl_state, dl_shared, batches,
                  round_ids):
-        smapped = shard_map(
+        smapped = jax.shard_map(
             core, mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(),
                       rspecs.batch_chunk(batches), P()),
             out_specs=(P(), P(), P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return smapped(params, cstate, shared, dl_state, dl_shared, batches,
                        round_ids)
@@ -536,11 +535,15 @@ def run_fl_fused(cfg: FLConfig,
                 acct.consume(rows[i], ledger, r)
             pending = None
 
+    client_shards = 0       # shards of the placed batch block's client axis
     for start, end in chunks:
         t_chunk = time.perf_counter()
         for _ in range(start, end):
             ledger.begin_round()
         batches = assemble(start, end)
+        if not client_shards:
+            blk = next(iter(batches.values()))
+            client_shards = c_pad // blk.sharding.shard_shape(blk.shape)[1]
         # host numpy, not jnp.arange: an eager jnp.arange bakes (start, end)
         # as constants and would compile a fresh tiny executable per chunk.
         round_ids = np.arange(start, end, dtype=np.int32)
@@ -575,6 +578,8 @@ def run_fl_fused(cfg: FLConfig,
     res.wall_s = time.time() - t0
     res.extra["engine"] = "fused"
     res.extra["use_pallas"] = use_pallas
+    res.extra["uplink_stats"] = [acct.uplink_stats[r]
+                                 for r in sorted(acct.uplink_stats)]
     res.extra["round_wall_s"] = round_wall
     res.extra["devices"] = ndev
     res.extra["scan_rounds"] = K
@@ -583,9 +588,7 @@ def run_fl_fused(cfg: FLConfig,
     res.extra["chunk_shapes"] = len({e - s for s, e in chunks})
     # One executable per distinct chunk length == zero mid-run recompiles;
     # asserted by tests and the CI recompile guard.
-    try:
-        res.extra["chunk_compiles"] = int(chunk_fn._cache_size())
-    except Exception:
-        res.extra["chunk_compiles"] = -1
+    res.extra["chunk_compiles"] = int(chunk_fn._cache_size())
+    res.extra["client_shards"] = client_shards
     res.extra.update(acct.metrics)
     return res

@@ -427,6 +427,8 @@ def _run_fl_loop(cfg: FLConfig, progress: Optional[Callable[[int, dict], None]] 
     res.wall_s = time.time() - t0
     res.extra["engine"] = "loop"
     res.extra["use_pallas"] = use_pallas
+    res.extra["uplink_stats"] = [acct.uplink_stats[r]
+                                 for r in sorted(acct.uplink_stats)]
     res.extra["round_wall_s"] = round_wall
     res.extra.update(acct.metrics)
     return res

@@ -83,7 +83,7 @@ def decode_wire_pallas(
     """Ghat = M (codes * scales / 127): dequantize the int8 coefficient wire
     inside the GEMM pass instead of materializing the f32 coefficients.
 
-    M: (l, k), codes: (k, m) int8, scales: (k, m/512);
+    M: (l, k), codes: (k, m) int8, scales: (m/512, k, 1);
     l % block_l == 0 and m % 512 == 0 (the wire's scale-block width).
     """
     l, k = M.shape
@@ -97,7 +97,7 @@ def decode_wire_pallas(
         in_specs=[
             pl.BlockSpec((block_l, k), lambda i, j: (i, 0)),
             pl.BlockSpec((k, 512), lambda i, j: (0, j)),
-            pl.BlockSpec((k, 1), lambda i, j: (0, j)),
+            pl.BlockSpec((None, k, 1), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_l, 512), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((l, m), M.dtype),

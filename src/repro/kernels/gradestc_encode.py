@@ -125,10 +125,10 @@ def encode_quant_pallas(
 
     The column tile is pinned at 512 (the wire's scale-block width) so each
     grid step owns exactly one scale column; ``ops.encode_quant`` checks the
-    VMEM budget fits this tile and falls back to the oracle otherwise.
+    VMEM budget fits this tile and refuses the shape otherwise.
 
     Args: M (l, k), G (l, m) with m % 512 == 0.
-    Returns (codes int8 (k, m), scales f32 (k, m/512), E (l, m) G.dtype) --
+    Returns (codes int8 (k, m), scales f32 (m/512, k, 1), E (l, m) G.dtype) --
     the residual is against the *shipped* (dequantized) coefficients, the
     error the server actually cannot see.
     """
@@ -145,12 +145,12 @@ def encode_quant_pallas(
         ],
         out_specs=[
             pl.BlockSpec((k, 512), lambda j: (0, j)),
-            pl.BlockSpec((k, 1), lambda j: (0, j)),
+            pl.BlockSpec((None, k, 1), lambda j: (j, 0, 0)),
             pl.BlockSpec((l, 512), lambda j: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, m), jnp.int8),
-            jax.ShapeDtypeStruct((k, m // 512), jnp.float32),
+            jax.ShapeDtypeStruct((m // 512, k, 1), jnp.float32),
             jax.ShapeDtypeStruct((l, m), G.dtype),
         ],
         interpret=interpret,

@@ -1,10 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles: block-shape selection against a VMEM budget, padding to tile
-multiples, and backend dispatch -- on TPU the kernels run compiled; elsewhere
-(this CPU container) they run in interpret mode or fall through to the
-pure-jnp reference (configurable), so the rest of the framework can call one
-API unconditionally.
+multiples, and backend dispatch -- on TPU the kernels run compiled,
+elsewhere in interpret mode.  ``use_kernel=False`` selects the pure-jnp
+``ref.py`` oracle; with ``use_kernel=True`` a shape or bit width no kernel
+covers raises instead of quietly running the oracle, so a kernel can never
+drop out of a device program unseen.
 """
 
 from __future__ import annotations
@@ -45,8 +46,9 @@ def choose_block_m(l: int, k: int, dtype=jnp.float32, budget: int = VMEM_BUDGET_
 
     VMEM model (bytes): l*k*s  +  2*l*bm*s  +  k*bm*s,  s = dtype size.
     Returns 0 when even bm=128 cannot fit (l too large for the single-pass
-    kernel; ops.encode then falls back to the XLA path, which tiles l
-    internally at the cost of reading G twice)."""
+    kernel; ops.encode then refuses the shape -- ``use_kernel=False`` runs
+    the XLA path, which tiles l internally at the cost of reading G
+    twice)."""
     s = jnp.dtype(dtype).itemsize
     fixed = l * k * s
     per_col = (2 * l + k) * s
@@ -75,7 +77,10 @@ def encode(
     l, k = M.shape
     bm = choose_block_m(l, k, G.dtype)
     if bm == 0:
-        return ref.encode_ref(M, G)   # l too large for single-pass VMEM
+        raise ValueError(
+            f"encode kernel: basis ({l}, {k}) leaves no 128-column tile "
+            f"inside the {VMEM_BUDGET_BYTES}-byte VMEM budget; "
+            "use_kernel=False runs the XLA path")
     # Never tile wider than the matrix itself: a small-m G only pays for
     # padding up to the next 128 multiple, not up to the VMEM-budget block.
     m128 = G.shape[1] + ((-G.shape[1]) % 128)
@@ -106,6 +111,29 @@ def decode(
     return out[:, :m] if pad else out
 
 
+#: row-tile cap of the row-tiled kernels: 256 x 512 f32 = 512 KiB per
+#: operand block, double-buffered well inside the VMEM budget
+_MAX_TILE_ROWS = 256
+
+
+def _row_tiling(rows: int) -> Tuple[int, int]:
+    """(padded rows, row tile) of a row-tiled kernel operand: the tile is
+    ``rows`` rounded up to the TPU's 8-row sublane tile, capped at
+    ``_MAX_TILE_ROWS``, and the rows are zero-padded to a multiple of it."""
+    tile = min(-(-rows // 8) * 8, _MAX_TILE_ROWS)
+    return -(-rows // tile) * tile, tile
+
+
+def _pad_rows(x2: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
+    """Zero-pad a (rows, ...) operand to ``_row_tiling``; returns the padded
+    array and its row tile."""
+    rows = x2.shape[0]
+    rows_p, tile = _row_tiling(rows)
+    if rows_p != rows:
+        x2 = jnp.pad(x2, [(0, rows_p - rows)] + [(0, 0)] * (x2.ndim - 1))
+    return x2, tile
+
+
 def block_quantize(
     g: jnp.ndarray, key: jax.Array, *, block: int = 512, bits: int = 8,
     use_kernel: bool = True, interpret: bool | None = None,
@@ -120,13 +148,13 @@ def block_quantize(
         return codes, scales, pad
     interp = (not _on_tpu()) if interpret is None else interpret
     rows = gp.shape[0] // block
-    br = rows if rows < 256 else 256
-    while rows % br:
-        br -= 1
+    rows_p, br = _row_tiling(rows)
+    extra = (rows_p - rows) * block
     codes, scales = block_quant_pallas(
-        gp, u, block=block, bits=bits, block_rows=br, interpret=interp
+        jnp.pad(gp, (0, extra)), jnp.pad(u, (0, extra)), block=block,
+        bits=bits, block_rows=br, interpret=interp
     )
-    return codes, scales, pad
+    return codes[: rows * block], scales[:rows], pad
 
 
 def quantize_update(
@@ -178,13 +206,12 @@ def block_dequantize(
     else:
         interp = (not _on_tpu()) if interpret is None else interpret
         rows = codes.shape[0] // block
-        br = rows if rows < 256 else 256
-        while rows % br:
-            br -= 1
+        rows_p, br = _row_tiling(rows)
         out = block_dequant_pallas(
-            codes, scales, block=block, bits=bits, block_rows=br,
-            interpret=interp, out_dtype=out_dtype,
-        )
+            jnp.pad(codes, (0, (rows_p - rows) * block)),
+            jnp.pad(scales, (0, rows_p - rows)), block=block, bits=bits,
+            block_rows=br, interpret=interp, out_dtype=out_dtype,
+        )[: rows * block]
     return out[: codes.shape[0] - pad] if pad else out
 
 
@@ -192,17 +219,26 @@ def block_dequantize(
 # packed wire dispatchers (DESIGN.md "Wire-format layer")
 # ---------------------------------------------------------------------------
 #
-# Each dispatcher pads to the (rows, WIRE_BLOCK) kernel layout, picks a row
-# tile, and crops the flat wire back to the exact word count the ledger
-# charges for.  ``use_kernel=False`` (or a shape/bit-width the kernels do not
-# cover) routes to the identical ref.py oracle -- the two paths are
+# Each dispatcher pads to the (rows, WIRE_BLOCK) kernel layout with rows a
+# multiple of the row tile (``_row_tiling``) and crops the flat wire back to the exact word count the ledger charges for.  The
+# kernels hand words over as int32; the uint32 wire is a bitcast here.
+# ``use_kernel=False`` routes to the ref.py oracle -- the two paths are
 # bit-exact, which tests/test_wire.py asserts per kernel.
 
-def _pick_rows(rows: int) -> int:
-    br = rows if rows < 256 else 256
-    while rows % br:
-        br -= 1
-    return br
+def _to_i32(words: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def _to_u32(words: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
+def _check_block_wire(bits: int, block: int) -> None:
+    if block != ref.WIRE_BLOCK or bits not in (2, 4, 8):
+        raise ValueError(
+            f"no wire kernel for bits={bits}, block={block} (kernels cover "
+            f"bits 2/4/8 at block {ref.WIRE_BLOCK}); use_kernel=False runs "
+            "the oracle")
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -224,12 +260,12 @@ def sign_wire(
     if pad:
         gp = jnp.pad(gp, (0, pad))
     rows = gp.shape[0] // ref.WIRE_BLOCK
-    words2, rowsums = sign_pack_pallas(
-        gp.reshape(rows, ref.WIRE_BLOCK),
-        block_rows=_pick_rows(rows), interpret=interp,
-    )
+    g2, tile = _pad_rows(gp.reshape(rows, ref.WIRE_BLOCK))
+    words2, rowsums = sign_pack_pallas(g2, block_rows=tile, interpret=interp)
     nw = -(-n // 32)
-    return words2.reshape(-1)[:nw], ref.pairwise_sum(rowsums) / n
+    # zero pad rows carry zero partials; the oracle's tree sees `rows` rows
+    return (_to_u32(words2.reshape(-1)[:nw]),
+            ref.pairwise_sum(rowsums[:rows, 0]) / n)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "use_kernel", "interpret"))
@@ -245,10 +281,8 @@ def sign_unwire(
     rows = -(-n // ref.WIRE_BLOCK)
     pad = rows * wpr - words.shape[0]
     wp = jnp.pad(words, (0, pad)) if pad else words
-    out = sign_unpack_pallas(
-        wp.reshape(rows, wpr), scale,
-        block_rows=_pick_rows(rows), interpret=interp,
-    )
+    w2, tile = _pad_rows(_to_i32(wp).reshape(rows, wpr))
+    out = sign_unpack_pallas(w2, scale, block_rows=tile, interpret=interp)
     return out.reshape(-1)[:n]
 
 
@@ -260,29 +294,28 @@ def block_quant_wire(
 
     Returns (words uint32, scales (ceil(n/block),) f32, pad).  The fused
     kernel covers ``block == WIRE_BLOCK`` and ``bits in {2, 4, 8}`` (bit
-    widths whose codes tile a 512-lane row evenly); other widths take the
-    jnp oracle, so any (bits >= 2, block) stays valid.  bits == 1 is
-    rejected: the symmetric signed code book has 2^(bits-1) - 1 = 0 levels
-    there -- a 1-bit wire is the *sign* format (``sign_wire``).
+    widths whose codes tile a 512-lane row evenly); other widths need
+    ``use_kernel=False`` (the jnp oracle, valid for any bits >= 2 and
+    block).  bits == 1 is rejected: the symmetric signed code book has
+    2^(bits-1) - 1 = 0 levels there -- a 1-bit wire is the *sign* format
+    (``sign_wire``).
     """
     assert bits >= 2, "1-bit quantization is the sign wire (ops.sign_wire)"
     n = g.shape[0]
     pad = (-n) % block
     gp = jnp.pad(g, (0, pad)) if pad else g
     u = jax.random.uniform(key, gp.shape, jnp.float32)
-    kernel_ok = (use_kernel and block == ref.WIRE_BLOCK
-                 and bits in (2, 4, 8))
-    if not kernel_ok:
+    if not use_kernel:
         words, scales = ref.quant_pack_ref(gp, u, block, bits)
         return words, scales, pad
+    _check_block_wire(bits, block)
     interp = (not _on_tpu()) if interpret is None else interpret
     rows = gp.shape[0] // block
-    words2, scales = quant_pack_pallas(
-        gp.reshape(rows, block).astype(jnp.float32),
-        u.reshape(rows, block),
-        bits=bits, block_rows=_pick_rows(rows), interpret=interp,
-    )
-    return words2.reshape(-1), scales, pad
+    g2, tile = _pad_rows(gp.reshape(rows, block).astype(jnp.float32))
+    u2, _ = _pad_rows(u.reshape(rows, block))
+    words2, scales = quant_pack_pallas(g2, u2, bits=bits, block_rows=tile,
+                                       interpret=interp)
+    return _to_u32(words2[:rows].reshape(-1)), scales[:rows, 0], pad
 
 
 def block_dequant_wire(
@@ -294,19 +327,28 @@ def block_dequant_wire(
     assert bits >= 2, "1-bit codes are the sign wire (ops.sign_unwire)"
     rows = scales.shape[0]
     n_p = rows * block
-    kernel_ok = (use_kernel and block == ref.WIRE_BLOCK
-                 and bits in (2, 4, 8))
-    if not kernel_ok:
+    if not use_kernel:
         out = ref.unpack_dequant_ref(words, scales, n_p, block, bits)
         out = out.astype(out_dtype)
     else:
+        _check_block_wire(bits, block)
         interp = (not _on_tpu()) if interpret is None else interpret
+        w2, tile = _pad_rows(_to_i32(words).reshape(rows, -1))
+        s2, _ = _pad_rows(scales.reshape(rows, 1))
         out = unpack_dequant_pallas(
-            words.reshape(rows, -1), scales,
-            bits=bits, block_rows=_pick_rows(rows), interpret=interp,
+            w2, s2, bits=bits, block_rows=tile, interpret=interp,
             out_dtype=out_dtype,
-        ).reshape(-1)
+        )[:rows].reshape(-1)
     return out[: n_p - pad] if pad else out
+
+
+def _scales_from_cols(s3: jnp.ndarray) -> jnp.ndarray:
+    """Kernel (nb, k, 1) scale columns -> the wire's (k, nb) scales."""
+    return s3[:, :, 0].T
+
+
+def _scales_to_cols(scales: jnp.ndarray) -> jnp.ndarray:
+    return scales.T[:, :, None]
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -321,10 +363,10 @@ def coeff_quant(
     interp = (not _on_tpu()) if interpret is None else interpret
     k, m = A.shape
     Ap, pad = _pad_cols(A.astype(jnp.float32), ref.WIRE_BLOCK)
-    codes, scales, ship = coeff_quant_pallas(Ap, interpret=interp)
+    codes, s3, ship = coeff_quant_pallas(Ap, interpret=interp)
     if pad:
         codes, ship = codes[:, :m], ship[:, :m]
-    return codes, scales, ship
+    return codes, _scales_from_cols(s3), ship
 
 
 @functools.partial(jax.jit, static_argnames=("wire_dtype", "use_kernel", "interpret"))
@@ -363,14 +405,17 @@ def encode_quant(
         return ref.encode_quant_ref(M, G)
     l, k = M.shape
     if choose_block_m(l, k, G.dtype) < 512:
-        return ref.encode_quant_ref(M, G)   # l too large for the 512 tile
+        raise ValueError(
+            f"encode_quant kernel: basis ({l}, {k}) does not fit a 512-column "
+            f"tile in the {VMEM_BUDGET_BYTES}-byte VMEM budget; "
+            "use_kernel=False runs the oracle")
     interp = (not _on_tpu()) if interpret is None else interpret
     m = G.shape[1]
     Gp, pad = _pad_cols(G, 512)
-    codes, scales, E = encode_quant_pallas(M, Gp, interpret=interp)
+    codes, s3, E = encode_quant_pallas(M, Gp, interpret=interp)
     if pad:
         codes, E = codes[:, :m], E[:, :m]
-    return codes, scales, E
+    return codes, _scales_from_cols(s3), E
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -387,5 +432,6 @@ def decode_wire(
     m = codes.shape[1]
     cp, pad = _pad_cols(codes, 512)
     bl = 256 if l % 256 == 0 else (128 if l % 128 == 0 else l)
-    out = decode_wire_pallas(M, cp, scales, block_l=bl, interpret=interp)
+    out = decode_wire_pallas(M, cp, _scales_to_cols(scales), block_l=bl,
+                             interpret=interp)
     return out[:, :m] if pad else out

@@ -4,6 +4,9 @@
 repeat invocations of the drivers/benchmarks skip XLA compilation entirely
 (the scan-fused round engine compiles one executable per chunk shape; with
 the cache warm even the first chunk of a fresh process is a disk hit).
+The cache lives in ``$JAX_COMPILATION_CACHE_DIR`` when that is set and in
+``<checkout>/.jax_cache`` otherwise -- one fixed path, since the path is
+part of what makes a later process find the entries again.
 
 ``CompileWatcher`` taps ``jax.monitoring`` to count backend compiles and
 accumulate the time spent in them -- this is how the round-engine benchmark
@@ -21,10 +24,11 @@ from typing import List, Optional, Tuple
 
 import jax
 
-__all__ = ["enable_compilation_cache", "CompileWatcher"]
+__all__ = ["enable_compilation_cache", "compilation_cache_dir",
+           "CompileWatcher"]
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro_jax_compilation")
+#: cache directory when $JAX_COMPILATION_CACHE_DIR is unset (gitignored)
+REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 #: monitoring event emitted once per XLA backend compile -- the recompile
 #: *count* tracks only these (one per executable built)
@@ -38,23 +42,24 @@ _PIPELINE_EVENTS = (
 )
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+def compilation_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` if set, else ``REPO_CACHE_DIR``."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(REPO_CACHE_DIR))
 
-    Default: ``$JAX_COMPILATION_CACHE_DIR`` or ``~/.cache/repro_jax_
-    compilation``.  The min-compile-time threshold is dropped to 0 so even
-    the small chunk executables of the scan engine are cached.  Idempotent;
-    returns the directory in use.
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`compilation_cache_dir`.
+
+    The min-compile-time threshold is dropped to 0 so even the small chunk
+    executables of the scan engine are cached.  Idempotent; returns the
+    directory in use.
     """
-    cache_dir = (cache_dir
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or _DEFAULT_DIR)
+    cache_dir = compilation_cache_dir()
     pathlib.Path(cache_dir).mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:      # option renamed across jax versions
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return cache_dir
 
 

@@ -17,13 +17,8 @@ __all__ = ["make_production_mesh", "make_local_mesh", "make_fl_mesh", "HW"]
 
 
 def _mk_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType / make_mesh(axis_types=...) only exist on newer
-    # jax; every mesh here is Auto-typed anyway, so fall back cleanly.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

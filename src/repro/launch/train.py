@@ -114,21 +114,18 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compilation-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache dir (default: "
-                    "$JAX_COMPILATION_CACHE_DIR or ~/.cache/"
-                    "repro_jax_compilation)")
     args = ap.parse_args(argv)
     # Host tuning first: XLA_FLAGS and logging knobs are frozen at the
     # first jax import, which happens inside _run_sim/_run_spmd.
     from repro.launch.env import configure_host
 
     configure_host(verbose=True)
-    # Persistent compile cache: repeat training invocations skip XLA
+    # Persistent compile cache ($JAX_COMPILATION_CACHE_DIR, else the
+    # checkout's .jax_cache): repeat training invocations skip XLA
     # compilation of the chunk/step executables entirely.
     from repro.launch.compile_cache import enable_compilation_cache
 
-    enable_compilation_cache(args.compilation_cache)
+    enable_compilation_cache()
     if args.mode == "sim":
         return _run_sim(args)
     return _run_spmd(args)

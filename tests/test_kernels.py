@@ -1,6 +1,8 @@
 """Pallas kernel validation: shape/dtype sweep vs the pure-jnp oracles
 (interpret=True executes the kernel bodies on CPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,9 +125,17 @@ class TestOpsWrappers:
                                 <= ops.VMEM_BUDGET_BYTES * 1.25)
 
     def test_encode_falls_back_for_huge_l(self, key):
-        """l too large for VMEM -> XLA path, still correct."""
-        M = _orthonormal(key, 29568 // 16, 8, jnp.float32)  # scaled-down check
+        """l too large for VMEM: the kernel refuses the shape instead of
+        quietly running the oracle, and the XLA path (use_kernel=False) is
+        the caller's explicit fallback; a scaled-down l runs the kernel."""
         assert ops.choose_block_m(29568, 64, jnp.float32) == 0
+        huge_M = jax.ShapeDtypeStruct((29568, 64), jnp.float32)
+        huge_G = jax.ShapeDtypeStruct((29568, 256), jnp.float32)
+        with pytest.raises(ValueError, match="VMEM"):
+            jax.eval_shape(ops.encode, huge_M, huge_G)
+        jax.eval_shape(functools.partial(ops.encode, use_kernel=False),
+                       huge_M, huge_G)
+        M = _orthonormal(key, 29568 // 16, 8, jnp.float32)  # scaled-down check
         G = jax.random.normal(key, (M.shape[0], 64))
         A, E = ops.encode(M, G)
         A0, E0 = ref.encode_ref(M, G)

@@ -168,8 +168,7 @@ class TestFusedLoopParity:
         res = run_fl(_cfg(engine="fused", rounds=9, scan_rounds=4,
                           eval_every=100))
         assert res.extra["chunk_shapes"] == 2      # {1, 4}
-        if res.extra["chunk_compiles"] >= 0:       # -1 = counter unavailable
-            assert res.extra["chunk_compiles"] == res.extra["chunk_shapes"]
+        assert res.extra["chunk_compiles"] == res.extra["chunk_shapes"]
         spans = res.extra["chunk_spans"]
         assert len(spans) == 3
         n_after, _ = watcher.since(mark, t_start=spans[-1][0])

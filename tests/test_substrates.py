@@ -143,3 +143,19 @@ class TestLaunchPlumbing:
         assert G.shape == (2, 3, l, n // l)
         back = _G_to_delta(G, lp, delta.shape)
         np.testing.assert_array_equal(np.asarray(back), np.asarray(delta))
+
+
+class TestCompileCacheDir:
+    """One fixed cache path: the environment's when set, else the
+    checkout's gitignored .jax_cache -- never a directory of its own."""
+
+    def test_env_unset_uses_checkout_dir(self, monkeypatch):
+        from repro.launch import compile_cache as cc
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.compilation_cache_dir() == os.path.join(root, ".jax_cache")
+
+    def test_env_set_is_used_verbatim(self, monkeypatch, tmp_path):
+        from repro.launch import compile_cache as cc
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.compilation_cache_dir() == str(tmp_path)

@@ -147,6 +147,20 @@ class TestQuantWire:
                                        bits)[:n]
         np.testing.assert_array_equal(np.asarray(via_wire), np.asarray(direct))
 
+    @pytest.mark.parametrize("bits,block", [(3, 512), (8, 256)])
+    def test_kernel_refuses_uncovered_widths(self, bits, block):
+        # no kernel for this (bits, block): raising beats quietly running
+        # the oracle inside a program that claims the kernel path
+        g = jnp.zeros((1024,), jnp.float32)
+        with pytest.raises(ValueError, match="no wire kernel"):
+            ops.block_quant_wire(g, jax.random.PRNGKey(0), bits=bits,
+                                 block=block, use_kernel=True)
+        w, s, pad = ops.block_quant_wire(g, jax.random.PRNGKey(0), bits=bits,
+                                         block=block, use_kernel=False)
+        with pytest.raises(ValueError, match="no wire kernel"):
+            ops.block_dequant_wire(w, s, pad, bits=bits, block=block,
+                                   use_kernel=True)
+
     def test_one_bit_is_rejected(self):
         # 2^(bits-1)-1 = 0 levels at bits=1: that wire is ops.sign_wire
         g = jnp.zeros((512,), jnp.float32)
@@ -207,6 +221,28 @@ class TestCoeffWire:
 # fused project -> int8 wire -> residual (SVDFed steady state)
 # ---------------------------------------------------------------------------
 
+def _assert_gemm_equal(a, b, M, A, G=None):
+    """``a`` and ``b`` both computed ``[G -] M @ A`` in f32, the kernel on
+    padded column tiles and the oracle on the unpadded m.  Where m fills at
+    least one 128-lane tile the two are bit-equal.  Narrower, XLA's CPU dot
+    for the oracle's (l, m < 128) output sums the k terms in another order
+    than the kernel's padded tile, so there the two are held to the
+    rounding bound of a k-term dot product,
+    2 * gamma_{k+1} * (|G| + |M| @ |A|), gamma_n = n*u / (1 - n*u),
+    u = 2**-24 -- in practice one ulp."""
+    if A.shape[1] >= 128:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        return
+    k = M.shape[1]
+    u = 2.0 ** -24
+    gamma = (k + 1) * u / (1 - (k + 1) * u)
+    mag = np.abs(np.asarray(M, np.float64)) @ np.abs(np.asarray(A, np.float64))
+    if G is not None:
+        mag = mag + np.abs(np.asarray(G, np.float64))
+    diff = np.abs(np.asarray(a, np.float64) - np.asarray(b, np.float64))
+    assert (diff <= 2 * gamma * mag).all(), float((diff - 2 * gamma * mag).max())
+
+
 class TestEncodeQuant:
     @pytest.mark.parametrize("l,k,m", [(128, 8, 512), (256, 16, 700),
                                        (64, 4, 100)])
@@ -217,12 +253,16 @@ class TestEncodeQuant:
         G = jnp.asarray(rng.standard_normal((l, m)), jnp.float32)
         co, so, Eo = ops.encode_quant(M, G, use_kernel=False)
         ck, sk, Ek = ops.encode_quant(M, G, use_kernel=True, interpret=True)
+        # the wire (codes, scales) is bit-exact
         np.testing.assert_array_equal(np.asarray(co), np.asarray(ck))
         np.testing.assert_array_equal(np.asarray(so), np.asarray(sk))
-        np.testing.assert_array_equal(np.asarray(Eo), np.asarray(Ek))
+        # E = G - M @ ship (the client's residual) and decode = M @ ship
+        # (the server's reconstruction) are GEMM outputs
+        ship = ref.coeff_dequant_ref(co, so)
+        _assert_gemm_equal(Eo, Ek, M, ship, G)
         go = ops.decode_wire(M, co, so, use_kernel=False)
         gk = ops.decode_wire(M, ck, sk, use_kernel=True, interpret=True)
-        np.testing.assert_array_equal(np.asarray(go), np.asarray(gk))
+        _assert_gemm_equal(go, gk, M, ship)
 
     def test_residual_consistent_with_decode(self):
         # E = G - M @ ship and decode(M, codes, scales) = M @ ship:
