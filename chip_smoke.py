@@ -201,19 +201,6 @@ def kernel_phase() -> None:
 # one method through run_fl
 # ---------------------------------------------------------------------------
 
-def _steady_round_ms(res, cfg) -> float:
-    """Mean wall per round over chunks whose length already compiled."""
-    from repro.fl.engine import plan_chunks
-
-    chunks = plan_chunks(cfg.rounds, cfg.eval_every, cfg.scan_rounds)
-    seen, walls = set(), []
-    for (s, e), (t0, t1) in zip(chunks, res.extra["chunk_spans"]):
-        if e - s in seen:
-            walls.append((t1 - t0) / (e - s))
-        seen.add(e - s)
-    return 1e3 * sum(walls) / len(walls) if walls else float("nan")
-
-
 def _custom_calls(dump_dir: pathlib.Path) -> int:
     """tpu_custom_call ops in the chunk programs the fused run compiled."""
     return sum(p.read_text().count("tpu_custom_call")
@@ -319,7 +306,6 @@ def run_method(label: str, method: str, method_kw: dict, watcher) -> None:
     if label in KERNEL_METHODS:
         check(calls > 0, f"{label}: no tpu_custom_call in the chunk program")
     print(f"method {label}: compile_s={compile_s:.1f} "
-          f"steady_round_ms={_steady_round_ms(fused, cfg):.2f} "
           f"uplink_bytes={fused.ledger.uplink_total:.0f} "
           f"ledger={ledger} tpu_custom_calls={calls} "
           f"eval_loss={fused.eval_loss[-1]:.6f} "

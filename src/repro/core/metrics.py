@@ -14,6 +14,10 @@ runtime and the benchmarks so every method is charged identically:
   * uplink  = client -> server (gradient direction);
     downlink = server -> client (model broadcast), counted once per round as
     the full model unless downlink compression is enabled.
+
+It also holds the runtime's two measurement hooks: the device->host fetch
+counter (:func:`host_fetch`) and :func:`span`, the named host interval that
+the FL engines mark their phases with (DESIGN.md "Tracing").
 """
 
 from __future__ import annotations
@@ -21,7 +25,20 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List
 
-__all__ = ["CommLedger", "bytes_h", "host_fetch", "host_sync_count", "reset_host_sync_count"]
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+__all__ = ["CommLedger", "bytes_h", "host_fetch", "host_sync_count",
+           "reset_host_sync_count", "span"]
+
+
+def span(name: str, **counts) -> TraceAnnotation:
+    """A named host interval with integer arguments, recorded in the
+    profiler's trace when one is being taken (``jax.profiler.trace``) and
+    about a microsecond of host time when none is.  Use it as a context
+    manager; an argument known only at the end goes in through
+    ``set_metadata`` before the block exits."""
+    return TraceAnnotation(name, **counts)
 
 
 #: Device->host transfer counter.  Every blocking fetch in the FL runtime is
@@ -32,12 +49,12 @@ _HOST_SYNCS = 0
 
 
 def host_fetch(x):
-    """Materialize a device value on the host, counting the sync."""
+    """Materialize a device value on the host, counting the sync (span
+    ``fl.host_fetch``, argument ``bytes``)."""
     global _HOST_SYNCS
     _HOST_SYNCS += 1
-    import numpy as _np
-
-    return _np.asarray(x)
+    with span("fl.host_fetch", bytes=int(getattr(x, "nbytes", 0))):
+        return np.asarray(x)
 
 
 def host_sync_count() -> int:

@@ -50,6 +50,13 @@ divide the mesh are padded in-jit with a mirrored client and masked out.
 The per-client Python loop (``simulation._run_fl_loop``) stays as the
 parity oracle; ``tests/test_round_engine.py`` and
 ``tests/test_sharded_engine.py`` pin every engine configuration to it.
+
+Tracing (DESIGN.md "Tracing"): the host loop marks its phases with
+``core.metrics.span`` -- ``fl.assemble`` / ``fl.draw`` (the chunk's batch
+block), ``fl.dispatch``, ``fl.drain``, ``fl.eval`` -- and the round body
+names its device phases ``fl_encode``, ``fl_aggregate`` and ``fl_server``
+(``jax.named_scope``; they reach each compiled op's ``op_name``).  Local
+training keeps its own name, ``jit(local_train)``.
 """
 
 from __future__ import annotations
@@ -64,7 +71,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.codecs import SERVER_CLIENT_ID
-from repro.core.metrics import host_fetch
+from repro.core.metrics import host_fetch, span
 
 from .compression import (
     RoundAccountant,
@@ -83,7 +90,17 @@ from .simulation import (
     select_round_clients,
 )
 
-__all__ = ["run_fl_fused", "plan_chunks"]
+__all__ = ["run_fl_fused", "plan_chunks", "ENCODE_SCOPE", "AGGREGATE_SCOPE",
+           "SERVER_SCOPE"]
+
+#: ``jax.named_scope`` names of the round body's phases after local
+#: training, shared by the single-device and sharded programs: the codec
+#: encode; the client deltas through the aggregated mean (collectives,
+#: ``reduce_stats``, ``update_shared``, ``from_wire``); the server step
+#: (server lr, downlink codec, new params, packed stats).
+ENCODE_SCOPE = "fl_encode"
+AGGREGATE_SCOPE = "fl_aggregate"
+SERVER_SCOPE = "fl_server"
 
 
 # ---------------------------------------------------------------------------
@@ -142,6 +159,22 @@ def _apply_downlink(dl_codecs, dl_state, dl_shared, avg, base_key):
     return new_dl_state, new_dl_shared, dl_reds
 
 
+def _server_step(server_lr, dl_codecs, params, flat_g, recon_mean, reds,
+                 new_cstate, new_shared, dl_state, dl_shared, base_key):
+    """The round's tail after aggregation, shared by both programs: scale
+    the mean update by the server lr, run the optional downlink codec,
+    apply the update and pack the round's stats.  Returns the scan step's
+    ``(carry, packed)``."""
+    avg = {p: recon_mean[p] * server_lr for p in flat_g}
+    new_dl_state, new_dl_shared, dl_reds = _apply_downlink(
+        dl_codecs, dl_state, dl_shared, avg, base_key)
+    new_flat = {p: flat_g[p] + avg[p].astype(flat_g[p].dtype) for p in flat_g}
+    new_params = _set_groups(params, new_flat)
+    packed = pack_round_stats(reds, dl_reds)
+    return (new_params, new_cstate, new_shared, new_dl_state,
+            new_dl_shared), packed
+
+
 def _build_chunk(arch, lr: float, server_lr: float, codecs, dl_codecs,
                  group_paths, seed: int, n_clients: int, n_sel: int):
     """Returns the jitted single-device ``chunk_fn``: a ``lax.scan`` of the
@@ -171,37 +204,35 @@ def _build_chunk(arch, lr: float, server_lr: float, codecs, dl_codecs,
         recon_mean: Dict[str, jnp.ndarray] = {}
         reds: Dict[str, jnp.ndarray] = {}
         for path in group_paths:
-            delta = flat_l[path] - flat_g[path][None]          # (C_sel, ...)
             codec = codecs.get(path)
-            if codec is None:
-                recon_mean[path] = jnp.sum(delta, 0) / delta.shape[0]
-                continue
-            wire = jax.vmap(codec.to_wire)(delta)
-            ckeys = jax.vmap(
-                lambda c, _co=codec: _co.per_client_key(base_key, c)
-            )(sel)
-            cst = jax.tree.map(take, cstate[path])
-            cst2, recon, stats = jax.vmap(
-                codec.encode, in_axes=(0, None, 0, 0)
-            )(cst, shared[path], ckeys, wire)
-            new_cstate[path] = jax.tree.map(put, cstate[path], cst2)
-            red = codec.reduce_stats(stats)
-            mean_wire = jnp.sum(recon, 0) / delta.shape[0]
-            new_shared[path] = codec.update_shared(shared[path], red,
-                                                   mean_wire)
-            recon_mean[path] = codec.from_wire(
-                mean_wire, flat_g[path].shape).astype(delta.dtype)
+            with jax.named_scope(AGGREGATE_SCOPE):
+                delta = flat_l[path] - flat_g[path][None]      # (C_sel, ...)
+                if codec is None:
+                    recon_mean[path] = jnp.sum(delta, 0) / delta.shape[0]
+                    continue
+            with jax.named_scope(ENCODE_SCOPE):
+                wire = jax.vmap(codec.to_wire)(delta)
+                ckeys = jax.vmap(
+                    lambda c, _co=codec: _co.per_client_key(base_key, c)
+                )(sel)
+                cst = jax.tree.map(take, cstate[path])
+                cst2, recon, stats = jax.vmap(
+                    codec.encode, in_axes=(0, None, 0, 0)
+                )(cst, shared[path], ckeys, wire)
+                new_cstate[path] = jax.tree.map(put, cstate[path], cst2)
+            with jax.named_scope(AGGREGATE_SCOPE):
+                red = codec.reduce_stats(stats)
+                mean_wire = jnp.sum(recon, 0) / delta.shape[0]
+                new_shared[path] = codec.update_shared(shared[path], red,
+                                                       mean_wire)
+                recon_mean[path] = codec.from_wire(
+                    mean_wire, flat_g[path].shape).astype(delta.dtype)
             reds[path] = red
 
-        avg = {p: recon_mean[p] * server_lr for p in group_paths}
-        new_dl_state, new_dl_shared, dl_reds = _apply_downlink(
-            dl_codecs, dl_state, dl_shared, avg, base_key)
-        new_flat = {p: flat_g[p] + avg[p].astype(flat_g[p].dtype)
-                    for p in group_paths}
-        new_params = _set_groups(params, new_flat)
-        packed = pack_round_stats(reds, dl_reds)
-        return (new_params, new_cstate, new_shared, new_dl_state,
-                new_dl_shared), packed
+        with jax.named_scope(SERVER_SCOPE):
+            return _server_step(server_lr, dl_codecs, params, flat_g,
+                                recon_mean, reds, new_cstate, new_shared,
+                                dl_state, dl_shared, base_key)
 
     # Only the carried state is donated: the int32 batch block has no
     # same-shape output to alias with, so donating it just trips XLA's
@@ -307,96 +338,99 @@ def _build_sharded_chunk(arch, lr: float, server_lr: float, codecs,
         state_meta: Dict[str, tuple] = {}
         stats_of: Dict[str, jnp.ndarray] = {}
         for path in group_paths:
-            delta = flat_l[path] - flat_g[path][None]          # (C_loc, ...)
             codec = codecs.get(path)
-            if codec is None:
-                sums[path] = jnp.sum(delta * cmask(delta), 0)
-                continue
-            wire = jax.vmap(codec.to_wire)(delta)
-            ckeys = jax.vmap(
-                lambda c, _co=codec: _co.per_client_key(base_key, c)
-            )(sel)
-            cst = jax.tree.map(lambda x: x[sel], cstate[path])
-            cst2, recon, stats = jax.vmap(
-                codec.encode, in_axes=(0, None, 0, 0)
-            )(cst, shared[path], ckeys, wire)
-            sums[path] = jnp.sum(recon * cmask(recon), 0)
-            int_cols.append(stats)
-            leaves, treedef = jax.tree.flatten(cst2)
-            state_cols[path] = [_as_i32(lf) for lf in leaves]
-            state_meta[path] = (treedef, [lf.shape for lf in leaves],
-                                [lf.dtype for lf in leaves])
+            with jax.named_scope(AGGREGATE_SCOPE):
+                delta = flat_l[path] - flat_g[path][None]      # (C_loc, ...)
+                if codec is None:
+                    sums[path] = jnp.sum(delta * cmask(delta), 0)
+                    continue
+            with jax.named_scope(ENCODE_SCOPE):
+                wire = jax.vmap(codec.to_wire)(delta)
+                ckeys = jax.vmap(
+                    lambda c, _co=codec: _co.per_client_key(base_key, c)
+                )(sel)
+                cst = jax.tree.map(lambda x: x[sel], cstate[path])
+                cst2, recon, stats = jax.vmap(
+                    codec.encode, in_axes=(0, None, 0, 0)
+                )(cst, shared[path], ckeys, wire)
+            with jax.named_scope(AGGREGATE_SCOPE):
+                sums[path] = jnp.sum(recon * cmask(recon), 0)
+                int_cols.append(stats)
+                leaves, treedef = jax.tree.flatten(cst2)
+                state_cols[path] = [_as_i32(lf) for lf in leaves]
+                state_meta[path] = (treedef, [lf.shape for lf in leaves],
+                                    [lf.dtype for lf in leaves])
 
-        # ---- collective 1: fused psum of every group's masked sum --------
-        flat_sums = jnp.concatenate(
-            [sums[p].reshape(-1).astype(jnp.float32) for p in group_paths])
-        flat_sums = jax.lax.psum(flat_sums, ax)
-        mean_of: Dict[str, jnp.ndarray] = {}
-        off = 0
-        for path in group_paths:
-            size = int(np.prod(sums[path].shape))
-            mean_of[path] = (flat_sums[off: off + size]
-                             .reshape(sums[path].shape) / n_sel)
-            off += size
-
-        # ---- collective 2: fused all-gather of [stats | state] rows ------
-        # (row i belongs to padded-selection lane i == client sel_full[i],
-        # which every shard already holds replicated -- no id column
-        # travels.  Raw-only methods have no rows at all and skip the
-        # collective entirely.)
-        for path in state_cols:
-            int_cols.extend(state_cols[path])
-        if int_cols:
-            gathered = jax.lax.all_gather(
-                jnp.concatenate(int_cols, axis=1), ax, axis=0, tiled=True)
-        else:
-            gathered = jnp.zeros((c_pad, 0), jnp.int32)
-        sel_all = sel_full
-        off = 0
-        for path in group_paths:
-            codec = codecs.get(path)
-            if codec is None:
-                continue
-            stats_of[path] = gathered[:n_sel, off: off + codec.client_stats_len]
-            off += codec.client_stats_len
-        new_cstate = dict(cstate)
-        for path, (treedef, shapes, dtypes) in state_meta.items():
-            upd = []
-            for shape, dtype in zip(shapes, dtypes):
-                size = int(np.prod(shape[1:], dtype=np.int64))
-                col = gathered[:, off: off + size]
-                upd.append(_from_i32(col, dtype,
-                                     (gathered.shape[0],) + shape[1:]))
+        # ---- the collectives and the replicated phase: aggregation ----
+        with jax.named_scope(AGGREGATE_SCOPE):
+            # ---- collective 1: fused psum of every group's masked sum ----
+            flat_sums = jnp.concatenate(
+                [sums[p].reshape(-1).astype(jnp.float32)
+                 for p in group_paths])
+            flat_sums = jax.lax.psum(flat_sums, ax)
+            mean_of: Dict[str, jnp.ndarray] = {}
+            off = 0
+            for path in group_paths:
+                size = int(np.prod(sums[path].shape))
+                mean_of[path] = (flat_sums[off: off + size]
+                                 .reshape(sums[path].shape) / n_sel)
                 off += size
-            new_cstate[path] = jax.tree.map(
-                lambda x, u: x.at[sel_all].set(u),
-                cstate[path], jax.tree.unflatten(treedef, upd))
 
-        # ---- replicated phase: identical on every shard ------------------
-        new_shared = dict(shared)
-        recon_mean: Dict[str, jnp.ndarray] = {}
-        reds: Dict[str, jnp.ndarray] = {}
-        for path in group_paths:
-            codec = codecs.get(path)
-            if codec is None:
-                recon_mean[path] = mean_of[path]
-                continue
-            red = codec.reduce_stats(stats_of[path])
-            new_shared[path] = codec.update_shared(shared[path], red,
-                                                   mean_of[path])
-            recon_mean[path] = codec.from_wire(
-                mean_of[path], flat_g[path].shape).astype(flat_g[path].dtype)
-            reds[path] = red
+            # ---- collective 2: fused all-gather of [stats | state] rows --
+            # (row i belongs to padded-selection lane i == client sel_full[i],
+            # which every shard already holds replicated -- no id column
+            # travels.  Raw-only methods have no rows at all and skip the
+            # collective entirely.)
+            for path in state_cols:
+                int_cols.extend(state_cols[path])
+            if int_cols:
+                gathered = jax.lax.all_gather(
+                    jnp.concatenate(int_cols, axis=1), ax, axis=0, tiled=True)
+            else:
+                gathered = jnp.zeros((c_pad, 0), jnp.int32)
+            sel_all = sel_full
+            off = 0
+            for path in group_paths:
+                codec = codecs.get(path)
+                if codec is None:
+                    continue
+                stats_of[path] = gathered[:n_sel,
+                                          off: off + codec.client_stats_len]
+                off += codec.client_stats_len
+            new_cstate = dict(cstate)
+            for path, (treedef, shapes, dtypes) in state_meta.items():
+                upd = []
+                for shape, dtype in zip(shapes, dtypes):
+                    size = int(np.prod(shape[1:], dtype=np.int64))
+                    col = gathered[:, off: off + size]
+                    upd.append(_from_i32(col, dtype,
+                                         (gathered.shape[0],) + shape[1:]))
+                    off += size
+                new_cstate[path] = jax.tree.map(
+                    lambda x, u: x.at[sel_all].set(u),
+                    cstate[path], jax.tree.unflatten(treedef, upd))
 
-        avg = {p: recon_mean[p] * server_lr for p in group_paths}
-        new_dl_state, new_dl_shared, dl_reds = _apply_downlink(
-            dl_codecs, dl_state, dl_shared, avg, base_key)
-        new_flat = {p: flat_g[p] + avg[p].astype(flat_g[p].dtype)
-                    for p in group_paths}
-        new_params = _set_groups(params, new_flat)
-        packed = pack_round_stats(reds, dl_reds)
-        return (new_params, new_cstate, new_shared, new_dl_state,
-                new_dl_shared), packed
+            # ---- replicated phase: identical on every shard --------------
+            new_shared = dict(shared)
+            recon_mean: Dict[str, jnp.ndarray] = {}
+            reds: Dict[str, jnp.ndarray] = {}
+            for path in group_paths:
+                codec = codecs.get(path)
+                if codec is None:
+                    recon_mean[path] = mean_of[path]
+                    continue
+                red = codec.reduce_stats(stats_of[path])
+                new_shared[path] = codec.update_shared(shared[path], red,
+                                                       mean_of[path])
+                recon_mean[path] = codec.from_wire(
+                    mean_of[path], flat_g[path].shape
+                ).astype(flat_g[path].dtype)
+                reds[path] = red
+
+        with jax.named_scope(SERVER_SCOPE):
+            return _server_step(server_lr, dl_codecs, params, flat_g,
+                                recon_mean, reds, new_cstate, new_shared,
+                                dl_state, dl_shared, base_key)
 
     def core(params, cstate, shared, dl_state, dl_shared, batches,
              round_ids):
@@ -500,44 +534,47 @@ def run_fl_fused(cfg: FLConfig,
         K*C_sel*steps small arrays: for the cheap codecs the round is
         host-bound, and this assembler (plus the stream draw behind it) is
         the host critical path that the K-round scan cannot amortize --
-        see the stream-side half of the fix in ``data/synthetic.py``."""
+        see the stream-side half of the fix in ``data/synthetic.py``.
+        Span ``fl.assemble`` (placement included), one ``fl.draw`` per
+        batch."""
         kc = end - start
         block: Dict[str, np.ndarray] = {}
-        for i, r in enumerate(range(start, end)):
-            for j, c in enumerate(sel_table[r]):
-                stream = su.streams[int(c)]
-                for s in range(cfg.local_steps):
-                    b = next(stream)
-                    if not block:
-                        block = {
-                            kk: np.empty(
-                                (kc, c_pad, cfg.local_steps) + np.shape(v),
-                                np.asarray(v).dtype)
-                            for kk, v in b.items()}
-                    for kk, v in b.items():
-                        block[kk][i, j, s] = v
-        if c_pad > n_sel:
-            for v in block.values():
-                v[:, n_sel:] = v[:, :1]
-        return place(block)
+        with span("fl.assemble", chunk=start, rounds=kc,
+                  batches=kc * n_sel * cfg.local_steps):
+            for i, r in enumerate(range(start, end)):
+                for j, c in enumerate(sel_table[r]):
+                    stream = su.streams[int(c)]
+                    for s in range(cfg.local_steps):
+                        with span("fl.draw", chunk=start, client=int(c)):
+                            b = next(stream)
+                        if not block:
+                            block = {
+                                kk: np.empty(
+                                    (kc, c_pad, cfg.local_steps)
+                                    + np.shape(v), np.asarray(v).dtype)
+                                for kk, v in b.items()}
+                        for kk, v in b.items():
+                            block[kk][i, j, s] = v
+            if c_pad > n_sel:
+                for v in block.values():
+                    v[:, n_sel:] = v[:, :1]
+            return place(block)
 
     chunks = plan_chunks(cfg.rounds, cfg.eval_every, K)
     res = FLResult([], [], [], [], ledger, 0.0)
-    round_wall = []
-    chunk_spans = []        # (perf_counter start, end) per chunk dispatch
     pending = None          # (stacked packed stats device array, start, end)
 
     def drain():
         nonlocal pending
         if pending is not None:
-            rows = host_fetch(pending[0])          # one fetch per chunk
-            for i, r in enumerate(range(pending[1], pending[2])):
-                acct.consume(rows[i], ledger, r)
+            with span("fl.drain", chunk=pending[1]):
+                rows = host_fetch(pending[0])          # one fetch per chunk
+                for i, r in enumerate(range(pending[1], pending[2])):
+                    acct.consume(rows[i], ledger, r)
             pending = None
 
     client_shards = 0       # shards of the placed batch block's client axis
     for start, end in chunks:
-        t_chunk = time.perf_counter()
         for _ in range(start, end):
             ledger.begin_round()
         batches = assemble(start, end)
@@ -547,8 +584,11 @@ def run_fl_fused(cfg: FLConfig,
         # host numpy, not jnp.arange: an eager jnp.arange bakes (start, end)
         # as constants and would compile a fresh tiny executable per chunk.
         round_ids = np.arange(start, end, dtype=np.int32)
-        out = chunk_fn(params, cstate, shared, dl_state, dl_shared, batches,
-                       round_ids)
+        with span("fl.dispatch", chunk=start, rounds=end - start) as sp:
+            built = chunk_fn._cache_size()
+            out = chunk_fn(params, cstate, shared, dl_state, dl_shared,
+                           batches, round_ids)
+            sp.set_metadata(new_programs=chunk_fn._cache_size() - built)
         params, cstate, shared, dl_state, dl_shared, packed = out
         # Consume the *previous* chunk's stats only after this chunk is
         # dispatched: the fetch (and the accounting behind it) overlaps
@@ -557,14 +597,12 @@ def run_fl_fused(cfg: FLConfig,
         pending = (packed, start, end)
         if hasattr(packed, "copy_to_host_async"):
             packed.copy_to_host_async()
-        dt = time.perf_counter() - t_chunk
-        chunk_spans.append((t_chunk, t_chunk + dt))
-        round_wall += [dt / (end - start)] * (end - start)
 
         rnd = end - 1
         if rnd % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
             drain()                       # ledger exact before reporting
-            la = host_fetch(eval_fn(params, eval_block))
+            with span("fl.eval", round=rnd):
+                la = host_fetch(eval_fn(params, eval_block))
             res.eval_rounds.append(rnd)
             res.eval_loss.append(float(la[0]))
             res.eval_acc.append(float(la[1]))
@@ -580,11 +618,9 @@ def run_fl_fused(cfg: FLConfig,
     res.extra["use_pallas"] = use_pallas
     res.extra["uplink_stats"] = [acct.uplink_stats[r]
                                  for r in sorted(acct.uplink_stats)]
-    res.extra["round_wall_s"] = round_wall
     res.extra["devices"] = ndev
     res.extra["scan_rounds"] = K
     res.extra["chunks"] = len(chunks)
-    res.extra["chunk_spans"] = chunk_spans
     res.extra["chunk_shapes"] = len({e - s for s, e in chunks})
     # One executable per distinct chunk length == zero mid-run recompiles;
     # asserted by tests and the CI recompile guard.

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codecs import SERVER_CLIENT_ID
-from repro.core.metrics import CommLedger, host_fetch
+from repro.core.metrics import CommLedger, host_fetch, span
 from repro.core.policy import make_policy
 from repro.data import client_batch_stream, make_task
 from repro.models import loss_fn, model, param_group_shapes
@@ -264,28 +264,32 @@ class _RunSetup:
 
 
 def _setup_run(cfg: FLConfig) -> _RunSetup:
-    arch = cfg.arch or default_tiny_arch()
-    task = make_task(vocab=arch.vocab, n_clients=cfg.n_clients, alpha=cfg.alpha,
-                     seed=cfg.seed)
-    params = model.init_params(arch, jax.random.PRNGKey(cfg.seed))
-    groups = param_group_shapes(arch)
-    policy = make_policy(groups, overrides=cfg.policy_overrides,
-                         coverage_target=cfg.coverage_target,
-                         min_params=cfg.min_params)
-    method = make_method(cfg.method, policy=policy, seed=cfg.seed, **cfg.method_kw)
-    streams = {c: client_batch_stream(task, c, cfg.batch, cfg.seq, cfg.seed)
-               for c in range(cfg.n_clients)}
-    eval_stream = client_batch_stream(task, -1, cfg.batch, cfg.seq, cfg.seed + 999)
-    eval_batches = [next(eval_stream) for _ in range(cfg.eval_batches)]
-    eval_block = {k: jnp.stack([b[k] for b in eval_batches])
-                  for k in eval_batches[0]}
-    return _RunSetup(
-        arch=arch, task=task, params=params, groups=groups,
-        group_paths=list(groups.keys()), policy=policy, method=method,
-        streams=streams, eval_block=eval_block,
-        eval_fn=make_batched_eval(arch), ledger=CommLedger(),
-        n_sel=max(1, int(round(cfg.participation * cfg.n_clients))),
-    )
+    with span("fl.setup", clients=cfg.n_clients):
+        arch = cfg.arch or default_tiny_arch()
+        task = make_task(vocab=arch.vocab, n_clients=cfg.n_clients,
+                         alpha=cfg.alpha, seed=cfg.seed)
+        params = model.init_params(arch, jax.random.PRNGKey(cfg.seed))
+        groups = param_group_shapes(arch)
+        policy = make_policy(groups, overrides=cfg.policy_overrides,
+                             coverage_target=cfg.coverage_target,
+                             min_params=cfg.min_params)
+        method = make_method(cfg.method, policy=policy, seed=cfg.seed,
+                             **cfg.method_kw)
+        streams = {c: client_batch_stream(task, c, cfg.batch, cfg.seq,
+                                          cfg.seed)
+                   for c in range(cfg.n_clients)}
+        eval_stream = client_batch_stream(task, -1, cfg.batch, cfg.seq,
+                                          cfg.seed + 999)
+        eval_batches = [next(eval_stream) for _ in range(cfg.eval_batches)]
+        eval_block = {k: jnp.stack([b[k] for b in eval_batches])
+                      for k in eval_batches[0]}
+        return _RunSetup(
+            arch=arch, task=task, params=params, groups=groups,
+            group_paths=list(groups.keys()), policy=policy, method=method,
+            streams=streams, eval_block=eval_block,
+            eval_fn=make_batched_eval(arch), ledger=CommLedger(),
+            n_sel=max(1, int(round(cfg.participation * cfg.n_clients))),
+        )
 
 
 def run_fl(cfg: FLConfig, progress: Optional[Callable[[int, dict], None]] = None) -> FLResult:
@@ -337,10 +341,8 @@ def _run_fl_loop(cfg: FLConfig, progress: Optional[Callable[[int, dict], None]] 
     local_train = make_local_train(su.arch, cfg.lr)
 
     res = FLResult([], [], [], [], ledger, 0.0)
-    round_wall = []
 
     for rnd in range(cfg.rounds):
-        t_round = time.perf_counter()
         ledger.begin_round()
         sel = [int(c) for c in
                np.asarray(select_round_clients(cfg.seed, rnd, C, n_sel))]
@@ -408,7 +410,6 @@ def _run_fl_loop(cfg: FLConfig, progress: Optional[Callable[[int, dict], None]] 
 
         # ---- the single host sync: same packed layout as the fused engine
         acct.consume(host_fetch(pack_round_stats(reds, dl_reds)), ledger, rnd)
-        round_wall.append(time.perf_counter() - t_round)
 
         if rnd % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
             # one jitted eval over the stacked block, one measured fetch --
@@ -429,6 +430,5 @@ def _run_fl_loop(cfg: FLConfig, progress: Optional[Callable[[int, dict], None]] 
     res.extra["use_pallas"] = use_pallas
     res.extra["uplink_stats"] = [acct.uplink_stats[r]
                                  for r in sorted(acct.uplink_stats)]
-    res.extra["round_wall_s"] = round_wall
     res.extra.update(acct.metrics)
     return res

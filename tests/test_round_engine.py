@@ -161,17 +161,24 @@ class TestFusedLoopParity:
         single extra XLA compile."""
         from repro.launch.compile_cache import CompileWatcher
 
+        import time
+
         watcher = CompileWatcher.install()
         mark = watcher.snapshot()
         # chunks: (0,1), (1,5), (5,9) -- the last repeats shape 4, so by
         # its dispatch every shape (and the eval program) is compiled.
+        # The eval after round 4 (each eval ends in a host sync) stamps
+        # the time from which no compile may follow.
+        stamps = {}
         res = run_fl(_cfg(engine="fused", rounds=9, scan_rounds=4,
-                          eval_every=100))
+                          eval_every=4),
+                     progress=lambda r, _: stamps.setdefault(
+                         r, time.perf_counter()))
         assert res.extra["chunk_shapes"] == 2      # {1, 4}
         assert res.extra["chunk_compiles"] == res.extra["chunk_shapes"]
-        spans = res.extra["chunk_spans"]
-        assert len(spans) == 3
-        n_after, _ = watcher.since(mark, t_start=spans[-1][0])
+        assert res.extra["chunks"] == 3
+        assert sorted(stamps) == [0, 4, 8]
+        n_after, _ = watcher.since(mark, t_start=stamps[4])
         assert n_after == 0, "steady-state chunk triggered an XLA compile"
 
     def test_pallas_encode_inside_engine_matches(self):
