@@ -27,12 +27,12 @@ rounds.  Chunks never span an eval round (``plan_chunks``), so parameters
 materialize exactly at eval points and trajectories / ledger bytes are
 invariant in K; a run compiles one executable per distinct chunk length
 (typically {1, K, remainder} -- measured via ``FLResult.extra
-["chunk_compiles"]``).  The chunk's stats fetch is deferred one chunk so
-the D2H transfer and the host-side accounting overlap the next chunk's
-device compute; all chunk inputs are donated (nothing is ever replayed --
-the speculation / spec-miss / donation-suppression machinery of the old
-per-round pipelined engine is gone, because the statics it speculated on
-no longer exist).
+["chunk_compiles"]``).  Chunk c+1's batches are drawn before chunk c's
+eval sync and its stats fetch is deferred one chunk, so the draws and the
+accounting overlap device compute; all chunk inputs are donated (nothing
+is ever replayed -- the speculation / spec-miss / donation-suppression
+machinery of the old per-round engine is gone, because the statics it
+speculated on no longer exist).
 
 Scaling across a device mesh (``FLConfig.devices > 1``): the same chunk
 runs under ``shard_map`` on a ``("data", "model")`` mesh
@@ -524,23 +524,23 @@ def run_fl_fused(cfg: FLConfig,
         lambda r: select_round_clients(cfg.seed, r, C, n_sel)
     )(jnp.arange(cfg.rounds)))
 
-    def assemble(start: int, end: int):
+    def assemble(start: int, end: int, in_flight: int):
         """Host side of a chunk: the stacked (Kc, C_pad, steps, B, S) batch
         block, drawn per round / per selected client in the same order as
         the reference loop (padding lanes replicate the round's first
-        selected client -- the in-jit mirror of ``sel[0]``).
+        selected client -- the in-jit mirror of ``sel[0]``), then placed.
 
         Fills one preallocated block per key instead of stacking
-        K*C_sel*steps small arrays: for the cheap codecs the round is
-        host-bound, and this assembler (plus the stream draw behind it) is
-        the host critical path that the K-round scan cannot amortize --
-        see the stream-side half of the fix in ``data/synthetic.py``.
-        Span ``fl.assemble`` (placement included), one ``fl.draw`` per
-        batch."""
+        K*C_sel*steps small arrays (the stream side of the draw is in
+        ``data/synthetic.py``).  The loop calls it for chunk c+1 while
+        chunk c computes, so the draws hide behind the device as long as
+        they take less than a chunk.  ``in_flight``: dispatched chunks not
+        yet synced, 0 only for the first chunk.  Span ``fl.assemble``
+        (placement included), one ``fl.draw`` per batch."""
         kc = end - start
         block: Dict[str, np.ndarray] = {}
         with span("fl.assemble", chunk=start, rounds=kc,
-                  batches=kc * n_sel * cfg.local_steps):
+                  batches=kc * n_sel * cfg.local_steps, in_flight=in_flight):
             for i, r in enumerate(range(start, end)):
                 for j, c in enumerate(sel_table[r]):
                     stream = su.streams[int(c)]
@@ -574,10 +574,10 @@ def run_fl_fused(cfg: FLConfig,
             pending = None
 
     client_shards = 0       # shards of the placed batch block's client axis
-    for start, end in chunks:
+    batches = assemble(*chunks[0], in_flight=0) if chunks else None
+    for (start, end), nxt in zip(chunks, chunks[1:] + [None]):
         for _ in range(start, end):
             ledger.begin_round()
-        batches = assemble(start, end)
         if not client_shards:
             blk = next(iter(batches.values()))
             client_shards = c_pad // blk.sharding.shard_shape(blk.shape)[1]
@@ -590,14 +590,14 @@ def run_fl_fused(cfg: FLConfig,
                            batches, round_ids)
             sp.set_metadata(new_programs=chunk_fn._cache_size() - built)
         params, cstate, shared, dl_state, dl_shared, packed = out
-        # Consume the *previous* chunk's stats only after this chunk is
-        # dispatched: the fetch (and the accounting behind it) overlaps
-        # this chunk's device compute.
+        # Before this iteration's host syncs (eval included): the next
+        # chunk's draws, then the previous chunk's stats, overlap this one.
+        if nxt is not None:
+            batches = assemble(*nxt, in_flight=1 + (pending is not None))
         drain()
         pending = (packed, start, end)
         if hasattr(packed, "copy_to_host_async"):
             packed.copy_to_host_async()
-
         rnd = end - 1
         if rnd % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
             drain()                       # ledger exact before reporting

@@ -151,6 +151,25 @@ def test_no_span_holds_an_assembly(traced):
                 assert not (s[1] <= a[1] and a[2] <= s[2]), (s, a)
 
 
+def test_next_chunk_is_assembled_before_the_eval_sync(traced):
+    """Every chunk here ends in an eval, so each assembly but the first
+    runs while the chunk before it is in flight: after its dispatch and
+    before its eval, never after the eval's fetch has drained the device."""
+    res, spans, _, _, _ = traced
+    chunks = plan_chunks(_CFG["rounds"], _CFG["eval_every"],
+                         _CFG["scan_rounds"])
+    assert [e - 1 for _, e in chunks] == res.eval_rounds
+    assemblies = sorted(_named(spans, "fl.assemble"), key=lambda s: s[1])
+    evals = {s[3]["round"]: s for s in _named(spans, "fl.eval")}
+    dispatches = {s[3]["chunk"]: s for s in _named(spans, "fl.dispatch")}
+    assert [a[3]["in_flight"] for a in assemblies] \
+        == [0] + [1] * (len(chunks) - 1)
+    assert assemblies[0][2] <= dispatches[chunks[0][0]][1]
+    for a, (prev_start, prev_end) in zip(assemblies[1:], chunks):
+        assert dispatches[prev_start][2] <= a[1]
+        assert a[2] <= evals[prev_end - 1][1], (a, evals[prev_end - 1])
+
+
 def test_wrapped_names_keep_their_call_paths(traced):
     res, _, syncs, calls, _ = traced
     assert calls["host_fetch"] == syncs
