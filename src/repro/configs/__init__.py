@@ -1,4 +1,4 @@
-"""Config registry: the 10 assigned architectures and the 4 input shapes.
+"""Config registry: the assigned architectures and the 4 input shapes.
 
 Each ``<arch>.py`` module defines ``CONFIG`` with the exact assigned
 hyperparameters and a source citation; ``CONFIG.reduced()`` is the smoke-test
@@ -23,6 +23,7 @@ _ARCH_MODULES = {
     "recurrentgemma-9b": "recurrentgemma_9b",
     "gemma3-1b": "gemma3_1b",
     "yi-34b": "yi_34b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 #: (arch, shape) pairs skipped with justification (DESIGN.md Sec. 4):
@@ -36,6 +37,7 @@ SKIPS: Dict[tuple, str] = {
     ("whisper-medium", "long_500k"): "full attention enc-dec; 500k ctx out of family scope",
     ("qwen2-vl-72b", "long_500k"): "pure full attention; no sub-quadratic path",
     ("yi-34b", "long_500k"): "pure full attention; no sub-quadratic path",
+    ("deepseek-v2-lite", "long_500k"): "pure full attention; no sub-quadratic path",
 }
 
 

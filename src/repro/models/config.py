@@ -19,7 +19,7 @@ __all__ = ["ArchConfig", "InputShape", "SHAPES"]
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                      # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                      # dense | moe | deepseek_v2 | ssm | hybrid | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -29,9 +29,34 @@ class ArchConfig:
     head_dim: int = 0                # 0 -> d_model // n_heads
 
     # --- MoE ---------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0               # routed experts the router scores
     experts_per_tok: int = 0
     capacity_factor: float = 1.25
+    #: experts this chip holds, 0..experts_held-1 (expert parallelism: the
+    #: layer routes over all n_experts and computes its own experts' part
+    #: of the result); 0 -> all n_experts
+    experts_held: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    #: always-on SwiGLU experts of width d_ff each, added to the routed
+    #: output (deepseek_v2: one SwiGLU of width n_shared_experts * d_ff)
+    n_shared_experts: int = 0
+    #: leading layers whose FFN is a dense SwiGLU of width dense_d_ff
+    #: instead of the expert layer (deepseek_v2)
+    first_k_dense_replace: int = 0
+    dense_d_ff: int = 0
+
+    # --- multi-head latent attention (deepseek_v2; kv_lora_rank > 0) ---------
+    kv_lora_rank: int = 0            # width of the normed latent c
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0        # one rotary key shared by all heads
+    v_head_dim: int = 0
+    #: YaRN rotary scaling of the rope dims (0 -> plain RoPE)
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- attention pattern --------------------------------------------------
     sliding_window: int = 0          # 0 = full attention
@@ -102,6 +127,11 @@ class ArchConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def held(self) -> int:
+        """Routed experts held on this chip."""
+        return self.experts_held or self.n_experts
+
+    @property
     def pattern(self) -> Tuple[str, ...]:
         return self.layer_pattern or ("global",)
 
@@ -131,6 +161,13 @@ class ArchConfig:
             vocab=min(self.vocab, 1024),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             experts_per_tok=min(self.experts_per_tok, 2) if self.experts_per_tok else 0,
+            experts_held=0,
+            first_k_dense_replace=min(self.first_k_dense_replace, 1),
+            dense_d_ff=min(self.dense_d_ff, 512),
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             encoder_layers=min(self.encoder_layers, 2) if self.encoder_layers else 0,
             encoder_seq=min(self.encoder_seq, 64) if self.encoder_layers else self.encoder_seq,
             vision_tokens=min(self.vision_tokens, 16) if self.vision_tokens else 0,

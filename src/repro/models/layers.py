@@ -134,13 +134,14 @@ def attention(
 ) -> jnp.ndarray:
     """Multi-head attention over full sequences (train / prefill).
 
-    q: (B, Sq, H, hd); k, v: (B, Skv, H, hd) (kv already GQA-repeated).
+    q: (B, Sq, H, hd); k: (B, Skv, H, hd) (kv already GQA-repeated); v:
+    (B, Skv, H, dv), whose head may be narrower than q's (latent attention).
     ``q_chunk`` > 0 bounds memory by scanning over query blocks (the flash-
     attention access pattern expressed in pure JAX; the materialized scores
     are (B, H, q_chunk, Skv) per step instead of (B, H, Sq, Skv)).
     """
     B, Sq, H, hd = q.shape
-    Skv = k.shape[1]
+    Skv, dv = k.shape[1], v.shape[-1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(hd)
 
     def blk(qb: jnp.ndarray, off) -> jnp.ndarray:
@@ -171,7 +172,7 @@ def attention(
 
     _, out = jax.lax.scan(body, None, (qs, jnp.arange(nq)),
                           unroll=nq if unroll else 1)
-    out = out.transpose(1, 0, 2, 3, 4).reshape(B, Sq_p, H, hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(B, Sq_p, H, dv)
     return out[:, :Sq] if pad else out
 
 

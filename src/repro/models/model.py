@@ -29,7 +29,7 @@ __all__ = [
     "param_group_shapes", "extra_inputs", "family_module", "count_params",
 ]
 
-_TRANSFORMER_FAMILIES = ("dense", "moe", "vlm")
+_TRANSFORMER_FAMILIES = ("dense", "moe", "vlm", "deepseek_v2")
 
 
 def family_module(cfg: ArchConfig):

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts FFN with top-k routing (granite-moe, dbrx).
+"""Mixture-of-Experts FFN with top-k routing (granite-moe, dbrx,
+deepseek-v2).
 
 GShard/Mesh-style capacity-based dispatch expressed as einsums so GSPMD can
 partition it: the expert axis E of the weight banks shards over the "model"
@@ -10,8 +11,20 @@ Tokens are processed in groups (``moe_group``); each group computes a
 C = ceil(S_g * top_k * capacity_factor / E).  Overflow tokens fall back to
 the residual stream (standard capacity-drop semantics).
 
+Expert parallelism's share (``ArchConfig.experts_held``): the layer holds
+experts ``first .. first + held - 1`` of ``n_experts``.  The router scores
+all ``n_experts`` and the capacity is reckoned over all of them, as on every
+chip of the deployment; only the held experts compute, so the layer returns
+their part of the routed result, and the parts of all shares add up to the
+whole layer.  Shared experts (``n_shared_experts``, DeepSeek-V2) see every
+token and are added once.
+
 The router runs in f32 and its weights are *excluded* from GradESTC
 compression (tiny but convergence-critical; see core/policy.py).
+
+Named scopes: ``moe_routed`` (router, dispatch and combine one-hots, the
+held experts' GEMMs) and ``moe_shared`` (the shared experts) reach every
+compiled op's ``op_name``.
 """
 
 from __future__ import annotations
@@ -23,56 +36,79 @@ import jax
 import jax.numpy as jnp
 
 from .config import ArchConfig
+from .layers import swiglu
 
 Params = Dict[str, Any]
 
-__all__ = ["init_moe_ffn", "moe_ffn", "router_load_balance_loss"]
+__all__ = ["init_moe_ffn", "init_shared_experts", "moe_ffn",
+           "router_load_balance_loss", "ROUTED_SCOPE", "SHARED_SCOPE"]
+
+ROUTED_SCOPE = "moe_routed"
+SHARED_SCOPE = "moe_shared"
 
 #: tokens per dispatch group; keeps the (S_g, E, C) one-hot bounded.
 MOE_GROUP = 4096
 
 
 def init_moe_ffn(cfg: ArchConfig, key: jax.Array, L: int) -> Params:
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts`` and the held experts' banks."""
+    D, F, E, Eh = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.held
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
     s, sf = 1.0 / math.sqrt(D), 1.0 / math.sqrt(F)
     return {
         "router": jax.random.normal(ks[0], (L, D, E), jnp.float32) * s,
-        "moe_wgate": jax.random.normal(ks[1], (L, E, D, F), dt) * s,
-        "moe_win": jax.random.normal(ks[2], (L, E, D, F), dt) * s,
-        "moe_wout": jax.random.normal(ks[3], (L, E, F, D), dt) * sf,
+        "moe_wgate": jax.random.normal(ks[1], (L, Eh, D, F), dt) * s,
+        "moe_win": jax.random.normal(ks[2], (L, Eh, D, F), dt) * s,
+        "moe_wout": jax.random.normal(ks[3], (L, Eh, F, D), dt) * sf,
     }
 
 
-def _dispatch_one_group(cfg: ArchConfig, x: jnp.ndarray, w: Params) -> jnp.ndarray:
-    """x: (S, D) one token group -> (S, D) expert-mixed output."""
+def init_shared_experts(cfg: ArchConfig, key: jax.Array, L: int) -> Params:
+    """The shared experts as one SwiGLU of width ``n_shared_experts * d_ff``."""
+    D, Fs = cfg.d_model, cfg.n_shared_experts * cfg.d_ff
+    dt = jnp.dtype(cfg.dtype)
+    ks = jax.random.split(key, 3)
+    s, sf = 1.0 / math.sqrt(D), 1.0 / math.sqrt(Fs)
+    return {
+        "shared_wgate": jax.random.normal(ks[0], (L, D, Fs), dt) * s,
+        "shared_win": jax.random.normal(ks[1], (L, D, Fs), dt) * s,
+        "shared_wout": jax.random.normal(ks[2], (L, Fs, D), dt) * sf,
+    }
+
+
+def _dispatch_one_group(cfg: ArchConfig, x: jnp.ndarray, w: Params,
+                        first: int = 0) -> jnp.ndarray:
+    """x: (S, D) one token group -> (S, D) output of the held experts."""
     S, D = x.shape
-    E, K = cfg.n_experts, cfg.experts_per_tok
+    E, K, Eh = cfg.n_experts, cfg.experts_per_tok, cfg.held
     C = max(1, int(math.ceil(S * K * cfg.capacity_factor / E)))
 
     logits = x.astype(jnp.float32) @ w["router"]            # (S, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, K)         # (S, K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
-    )
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
+        )
 
-    # (S, K, E) one-hot of chosen experts
-    sel = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)
+    # (S, K, Eh) one-hot of the chosen experts held here: a choice of an
+    # expert held elsewhere is a row of zeros, and takes no slot here
+    sel = jax.nn.one_hot(expert_idx - first if first else expert_idx, Eh,
+                         dtype=jnp.float32)
     # position of each (token, choice) within its expert's buffer:
     # cumulative count over the flattened (choice-major) priority order.
-    selk = sel.transpose(1, 0, 2).reshape(K * S, E)          # choice-major
-    pos_flat = jnp.cumsum(selk, axis=0) - selk               # (K*S, E)
-    pos = pos_flat.reshape(K, S, E).transpose(1, 0, 2)       # (S, K, E)
+    selk = sel.transpose(1, 0, 2).reshape(K * S, Eh)         # choice-major
+    pos_flat = jnp.cumsum(selk, axis=0) - selk               # (K*S, Eh)
+    pos = pos_flat.reshape(K, S, Eh).transpose(1, 0, 2)      # (S, K, Eh)
     within_cap = (pos < C) & (sel > 0)
 
     dt = x.dtype
     slot = jax.nn.one_hot(jnp.sum(pos * sel, axis=-1).astype(jnp.int32), C,
                           dtype=dt)                          # (S, K, C)
-    sel_kept = (sel * within_cap).astype(dt)                 # (S, K, E)
+    sel_kept = (sel * within_cap).astype(dt)                 # (S, K, Eh)
 
-    # dispatch (S, E, C): token s occupies slot c of expert e.  Kept in the
+    # dispatch (S, Eh, C): token s occupies slot c of expert e.  Kept in the
     # model dtype -- these are the largest activations of the MoE block.
     if cfg.moe_stop_gradient_dispatch:
         # The one-hot structure is integer-valued: routing indices carry no
@@ -93,24 +129,33 @@ def _dispatch_one_group(cfg: ArchConfig, x: jnp.ndarray, w: Params) -> jnp.ndarr
             "ske,skc->sec", sel_kept * gate_vals[..., None].astype(dt), slot
         )
 
-    xe = jnp.einsum("sec,sd->ecd", dispatch, x)              # (E, C, D)
+    xe = jnp.einsum("sec,sd->ecd", dispatch, x)              # (Eh, C, D)
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, w["moe_wgate"])) * jnp.einsum(
         "ecd,edf->ecf", xe, w["moe_win"]
     )
-    ye = jnp.einsum("ecf,efd->ecd", h, w["moe_wout"])        # (E, C, D)
+    ye = jnp.einsum("ecf,efd->ecd", h, w["moe_wout"])        # (Eh, C, D)
     return jnp.einsum("sec,ecd->sd", combine, ye)            # (S, D)
 
 
-def moe_ffn(cfg: ArchConfig, x: jnp.ndarray, w: Params) -> jnp.ndarray:
-    """x: (B, S, D) -> (B, S, D).  Groups tokens to bound dispatch memory."""
+def moe_ffn(cfg: ArchConfig, x: jnp.ndarray, w: Params,
+            first: int = 0) -> jnp.ndarray:
+    """x: (B, S, D) -> (B, S, D): the held experts' part of the routed
+    output (experts ``first ..``), plus the shared experts.  Groups tokens
+    to bound dispatch memory."""
     B, S, D = x.shape
     T = B * S
-    g = min(cfg.moe_group or MOE_GROUP, T)
-    while T % g:
-        g -= 1
-    xg = x.reshape(T // g, g, D)
-    yg = jax.vmap(lambda t: _dispatch_one_group(cfg, t, w))(xg)
-    return yg.reshape(B, S, D)
+    with jax.named_scope(ROUTED_SCOPE):
+        g = min(cfg.moe_group or MOE_GROUP, T)
+        while T % g:
+            g -= 1
+        xg = x.reshape(T // g, g, D)
+        yg = jax.vmap(lambda t: _dispatch_one_group(cfg, t, w, first))(xg)
+        y = yg.reshape(B, S, D)
+    if cfg.n_shared_experts:
+        with jax.named_scope(SHARED_SCOPE):
+            y = y + swiglu(x, w["shared_wgate"], w["shared_win"],
+                           w["shared_wout"])
+    return y
 
 
 def router_load_balance_loss(cfg: ArchConfig, x: jnp.ndarray, w: Params) -> jnp.ndarray:

@@ -11,6 +11,13 @@ iterated with ``lax.scan`` so the HLO stays compact for 40..80-layer configs
 Per-layer heterogeneity (gemma3's local/global pattern) is carried as a
 per-layer ``window`` array scanned alongside the weights -- the mask is
 computed with dynamic window arithmetic so one traced body serves both kinds.
+
+deepseek-v2 (``kv_lora_rank`` > 0): multi-head latent attention (``mla.py``)
+in every layer; the first ``first_k_dense_replace`` layers have a dense
+SwiGLU (``dense_mlp_*`` leaves, stacked over those layers) and run before
+the scan, which covers the expert layers (``router``/``moe_*``/``shared_*``
+leaves, stacked over them); the attention and norm leaves stack over all
+layers.
 """
 
 from __future__ import annotations
@@ -34,7 +41,8 @@ from .layers import (
     rms_norm,
     swiglu,
 )
-from .moe import init_moe_ffn, moe_ffn
+from . import mla
+from .moe import init_moe_ffn, init_shared_experts, moe_ffn
 
 Params = Dict[str, Any]
 
@@ -73,6 +81,43 @@ def _init_dense_layer(cfg: ArchConfig, key: jax.Array, L: int) -> Params:
     return p
 
 
+def _init_latent_layers(cfg: ArchConfig, key: jax.Array) -> Params:
+    """deepseek-v2's layer leaves (module docstring)."""
+    L, Ld = cfg.n_layers, cfg.first_k_dense_replace
+    D, Fd = cfg.d_model, cfg.dense_d_ff
+    dt = _dtype(cfg)
+    ka, kd, km, ks = jax.random.split(key, 4)
+    p = {"ln_attn": jnp.zeros((L, D), dt), "ln_mlp": jnp.zeros((L, D), dt)}
+    p.update(mla.init_mla(cfg, ka, L))
+    if Ld:
+        kg = jax.random.split(kd, 3)
+        s, sf = 1.0 / math.sqrt(D), 1.0 / math.sqrt(Fd)
+        p.update({
+            "dense_mlp_wgate": jax.random.normal(kg[0], (Ld, D, Fd), dt) * s,
+            "dense_mlp_win": jax.random.normal(kg[1], (Ld, D, Fd), dt) * s,
+            "dense_mlp_wout": jax.random.normal(kg[2], (Ld, Fd, D), dt) * sf,
+        })
+    p.update(init_moe_ffn(cfg, km, L - Ld))
+    if cfg.n_shared_experts:
+        p.update(init_shared_experts(cfg, ks, L - Ld))
+    return p
+
+
+def _expert_leaf(name: str) -> bool:
+    return name == "router" or name.startswith(("moe_", "shared_"))
+
+
+def _split_dense(cfg: ArchConfig, layers: Params):
+    """([leaves of dense layer i], leaves of the expert-layer stack): the
+    leaves stacked over every layer are indexed and sliced."""
+    Ld = cfg.first_k_dense_replace
+    dense = [{k: v[i] for k, v in layers.items() if not _expert_leaf(k)}
+             for i in range(Ld)]
+    stack = {k: (v if _expert_leaf(k) else v[Ld:])
+             for k, v in layers.items() if not k.startswith("dense_")}
+    return dense, stack
+
+
 def padded_vocab(cfg: ArchConfig) -> int:
     """Megatron-style vocab padding so embed/head shard over "model"
     (SPerf switch; 0 = off)."""
@@ -88,7 +133,8 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Params:
     D, V, L = cfg.d_model, padded_vocab(cfg), cfg.n_layers
     params: Params = {
         "embed": jax.random.normal(kE, (V, D), dt) * 0.02,
-        "layers": _init_dense_layer(cfg, kL, L),
+        "layers": (_init_latent_layers(cfg, kL) if cfg.kv_lora_rank
+                   else _init_dense_layer(cfg, kL, L)),
         "ln_f": jnp.zeros((D,), dt),
     }
     if not cfg.tie_embeddings:
@@ -126,6 +172,9 @@ def layer_fwd(
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
 
     h = rms_norm(x, w["ln_attn"], cfg.norm_eps)
+    if cfg.kv_lora_rank:
+        x = x + mla.mla_attention(cfg, h, w, positions, window, q_chunk)
+        return x + _ffn(cfg, rms_norm(x, w["ln_mlp"], cfg.norm_eps), w)
     q = (h @ w["attn_wq"]).reshape(B, S, H, hd)
     k = (h @ w["attn_wk"]).reshape(B, S, KV, hd)
     v = (h @ w["attn_wv"]).reshape(B, S, KV, hd)
@@ -145,11 +194,16 @@ def layer_fwd(
     x = x + o.reshape(B, S, H * hd) @ w["attn_wo"]
 
     h = rms_norm(x, w["ln_mlp"], cfg.norm_eps)
+    return x + _ffn(cfg, h, w)
+
+
+def _ffn(cfg: ArchConfig, h: jnp.ndarray, w: Params) -> jnp.ndarray:
+    if "dense_mlp_wgate" in w:
+        return swiglu(h, w["dense_mlp_wgate"], w["dense_mlp_win"],
+                      w["dense_mlp_wout"])
     if cfg.n_experts:
-        y = moe_ffn(cfg, h, w)
-    else:
-        y = swiglu(h, w["mlp_wgate"], w["mlp_win"], w["mlp_wout"])
-    return x + y
+        return moe_ffn(cfg, h, w)
+    return swiglu(h, w["mlp_wgate"], w["mlp_win"], w["mlp_wout"])
 
 
 def forward_hidden(
@@ -180,7 +234,13 @@ def forward_hidden(
         body_fn = jax.checkpoint(
             body_fn, policy=jax.checkpoint_policies.nothing_saveable
         )
-    x, _ = jax.lax.scan(body_fn, x, (params["layers"], windows),
+    layers = params["layers"]
+    if cfg.first_k_dense_replace:
+        dense, layers = _split_dense(cfg, layers)
+        for i, w in enumerate(dense):
+            x, _ = body_fn(x, (w, windows[i]))
+        windows = windows[len(dense):]
+    x, _ = jax.lax.scan(body_fn, x, (layers, windows),
                        unroll=cfg.scan_unroll)
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
@@ -203,6 +263,16 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int, length=0) -> KVCache:
     (for dry-runs the cache content is abstract)."""
     dt = _dtype(cfg)
     L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
+    if cfg.kv_lora_rank:
+        # expanded per-head keys and values (the latent-absorbed cache is a
+        # serving optimisation no training path runs)
+        H = cfg.n_heads
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return KVCache(
+            k=jnp.zeros((L, batch, max_len, H, qk), dt),
+            v=jnp.zeros((L, batch, max_len, H, cfg.v_head_dim), dt),
+            length=jnp.asarray(length, jnp.int32),
+        )
     return KVCache(
         k=jnp.zeros((L, batch, max_len, KV, hd), dt),
         v=jnp.zeros((L, batch, max_len, KV, hd), dt),
@@ -231,6 +301,16 @@ def decode_step(
         x, = carry
         w, window, kc, vc = lw
         h = rms_norm(x, w["ln_attn"], cfg.norm_eps)
+        if cfg.kv_lora_rank:
+            with jax.named_scope(mla.MLA_SCOPE):
+                q, k, v = mla.project(cfg, h, w, pos)
+                kc = jax.lax.dynamic_update_slice(kc, k, (0, cache.length, 0, 0))
+                vc = jax.lax.dynamic_update_slice(vc, v, (0, cache.length, 0, 0))
+                o = decode_attention(q, kc, vc, cache.length + 1, window=window,
+                                     softmax_scale=mla.softmax_scale(cfg))
+                x = x + o.reshape(B, 1, -1) @ w["attn_wo"]
+            h2 = rms_norm(x, w["ln_mlp"], cfg.norm_eps)
+            return (x + _ffn(cfg, h2, w),), (kc, vc)
         q = (h @ w["attn_wq"]).reshape(B, 1, H, hd)
         k = (h @ w["attn_wk"]).reshape(B, 1, KV, hd)
         v = (h @ w["attn_wv"]).reshape(B, 1, KV, hd)
@@ -251,15 +331,21 @@ def decode_step(
             )
         x = x + o.reshape(B, 1, H * hd) @ w["attn_wo"]
         h2 = rms_norm(x, w["ln_mlp"], cfg.norm_eps)
-        if cfg.n_experts:
-            y = moe_ffn(cfg, h2, w)
-        else:
-            y = swiglu(h2, w["mlp_wgate"], w["mlp_win"], w["mlp_wout"])
-        return (x + y,), (kc, vc)
+        return (x + _ffn(cfg, h2, w),), (kc, vc)
 
-    (x,), (k_new, v_new) = jax.lax.scan(
-        body, (x,), (params["layers"], windows, cache.k, cache.v)
-    )
+    layers, ck, cv = params["layers"], cache.k, cache.v
+    Ld = cfg.first_k_dense_replace
+    dense_kv = []
+    if Ld:
+        dense, layers = _split_dense(cfg, layers)
+        for i, w in enumerate(dense):
+            (x,), kv = body((x,), (w, windows[i], ck[i], cv[i]))
+            dense_kv.append(kv)
+        windows, ck, cv = windows[Ld:], ck[Ld:], cv[Ld:]
+    (x,), (k_new, v_new) = jax.lax.scan(body, (x,), (layers, windows, ck, cv))
+    if dense_kv:
+        k_new = jnp.concatenate([jnp.stack([k for k, _ in dense_kv]), k_new])
+        v_new = jnp.concatenate([jnp.stack([v for _, v in dense_kv]), v_new])
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = (x @ head).astype(jnp.float32)[..., : cfg.vocab]
@@ -270,8 +356,48 @@ def decode_step(
 # compression-policy hook
 # --------------------------------------------------------------------------
 
+def _latent_group_shapes(cfg: ArchConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]:
+    D, F, H, L = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_layers
+    Ld, r = cfg.first_k_dense_replace, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    Lm, E, Eh, Fd = L - Ld, cfg.n_experts, cfg.held, cfg.dense_d_ff
+    Fs = cfg.n_shared_experts * F
+    groups = {
+        "layers/ln_attn": ((D,), L),
+        "layers/ln_mlp": ((D,), L),
+        "layers/attn_wq": ((D, H * (nope + rope)), L),
+        "layers/attn_wkv_a": ((D, r + rope), L),
+        "layers/attn_kv_norm": ((r,), L),
+        "layers/attn_wkv_b": ((r, H * (nope + dv)), L),
+        "layers/attn_wo": ((H * dv, D), L),
+        "layers/router": ((D, E), Lm),
+        "layers/moe_wgate": ((Eh, D, F), Lm),
+        "layers/moe_win": ((Eh, D, F), Lm),
+        "layers/moe_wout": ((Eh, F, D), Lm),
+        "embed": ((padded_vocab(cfg), D), 1),
+        "ln_f": ((D,), 1),
+    }
+    if Ld:
+        groups.update({
+            "layers/dense_mlp_wgate": ((D, Fd), Ld),
+            "layers/dense_mlp_win": ((D, Fd), Ld),
+            "layers/dense_mlp_wout": ((Fd, D), Ld),
+        })
+    if Fs:
+        groups.update({
+            "layers/shared_wgate": ((D, Fs), Lm),
+            "layers/shared_win": ((D, Fs), Lm),
+            "layers/shared_wout": ((Fs, D), Lm),
+        })
+    if not cfg.tie_embeddings:
+        groups["head"] = ((D, padded_vocab(cfg)), 1)
+    return groups
+
+
 def param_group_shapes(cfg: ArchConfig) -> Dict[str, Tuple[Tuple[int, ...], int]]:
     """{group: (per-layer shape, stack)} for the GradESTC policy."""
+    if cfg.kv_lora_rank:
+        return _latent_group_shapes(cfg)
     D, F, H, KV, hd, L = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.n_layers
     groups = {
         "layers/attn_wq": ((D, H * hd), L),
@@ -284,11 +410,11 @@ def param_group_shapes(cfg: ArchConfig) -> Dict[str, Tuple[Tuple[int, ...], int]
         "ln_f": ((D,), 1),
     }
     if cfg.n_experts:
-        E = cfg.n_experts
+        E, Eh = cfg.n_experts, cfg.held
         groups.update({
-            "layers/moe_wgate": ((E, D, F), L),
-            "layers/moe_win": ((E, D, F), L),
-            "layers/moe_wout": ((E, F, D), L),
+            "layers/moe_wgate": ((Eh, D, F), L),
+            "layers/moe_win": ((Eh, D, F), L),
+            "layers/moe_wout": ((Eh, F, D), L),
             "layers/router": ((D, E), L),
         })
     else:

@@ -132,6 +132,22 @@ def test_moe_capacity_drops_are_the_only_decode_divergence():
     np.testing.assert_allclose(np.asarray(dec), np.asarray(logits), atol=1e-3)
 
 
+@pytest.mark.parametrize("name", ["granite-moe-1b-a400m", "deepseek-v2-lite"])
+def test_param_group_shapes_cover_every_leaf(name, built):
+    """The FL policy and the server step see only the listed groups, so
+    every leaf ``init_params`` returns is listed, with its per-layer shape
+    and its stack (a leaf of ``layers`` stacks on its leading axis)."""
+    cfg, params = built(name)
+    leaves = {}
+    for path, x in jax.tree_util.tree_flatten_with_path(params)[0]:
+        keys = [p.key for p in path]
+        if keys[0] == "layers":
+            leaves["/".join(keys)] = (tuple(x.shape[1:]), x.shape[0])
+        else:
+            leaves["/".join(keys)] = (tuple(x.shape), 1)
+    assert model.param_group_shapes(cfg) == leaves
+
+
 def test_gemma3_local_global_pattern():
     cfg = get_config("gemma3-1b")
     kinds = cfg.layer_kinds()
